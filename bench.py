@@ -1,6 +1,6 @@
 """Headline benchmark: allreduce algorithm bandwidth, host plane.
 
-Config #1 from BASELINE.md: allreduce, float32, 64 MiB payload, 2 ranks,
+Config #1: allreduce, float32, 64 MiB payload, 2 ranks,
 host transport on localhost — the reference's own benchmark methodology
 (p50 of timed iterations after warmup, verified first iteration). "Host"
 because the transport routes bulk payloads over its same-host shm plane
@@ -13,7 +13,7 @@ allreduce_ring_chunked` at the same config: measured live when the
 reference build exists at build-ref/ (run `cmake -S /root/reference -B
 build-ref -G Ninja -DBUILD_BENCHMARK=ON -DUSE_REDIS=OFF && cmake --build
 build-ref`), otherwise against the value recorded on this host
-(0.620 GB/s, see BASELINE.md).
+(RECORDED_REFERENCE_GBPS).
 
 Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline",
 "spread", "runs"} — value is the median of five full measurements taken

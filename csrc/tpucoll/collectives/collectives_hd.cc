@@ -675,7 +675,7 @@ void directReduceScatter(Context* ctx, plan::Plan& plan, char* work,
 // rounds, and receives the finished result. At the tiny payloads this
 // tier serves the two fold messages cost ~1 alpha each, keeping total
 // latency at log2(p2)+2 rounds vs fold-HD's 2*log2(p2)+2 — the same
-// 2x round advantage the pow-2 path measures (BASELINE.md).
+// 2x round advantage the pow-2 path has.
 //
 // Bitwise identity across ranks: survivors enter the log rounds with
 // subgroup-identical values; at each round both partners compute
@@ -787,8 +787,7 @@ void halvingDoublingAllreduce(Context* ctx, plan::Plan& plan, char* work,
                     fuseOk);
     return;
   }
-  // Non-power-of-2 strategy. Loopback-measured crossover (BASELINE.md,
-  // P=6): fold's fewer messages win while per-message overhead dominates;
+  // Non-power-of-2 strategy. Loopback-measured crossover (P=6): fold's fewer messages win while per-message overhead dominates;
   // binary-blocks' proportional byte work wins once payloads are large.
   // TPUCOLL_HD_NP2=blocks|fold forces either; otherwise the installed
   // tuning table's measured hd_fold/hd_blocks curves decide when both

@@ -489,7 +489,7 @@ void alltoall(AlltoallOptions& opts) {
   // Crossover: Bruck's ceil(log2 P) rounds win while per-block payload
   // is latency-dominated; the pairwise exchange's P-1 single-hop
   // rounds win once bandwidth dominates (each Bruck block travels up
-  // to log2 P hops). Loopback P=8 measurement (BASELINE.md r4): p50
+  // to log2 P hops). Loopback P=8 measurement (round 4): p50
   // crosses below 2 KiB blocks on the shared-core host (Bruck 2.3x
   // better at 512 B), while min latency favors Bruck through ~4 KiB
   // (8.6 vs 246 us at 512 B — 28x). Default follows the p50 crossover;

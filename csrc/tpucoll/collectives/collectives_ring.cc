@@ -474,7 +474,7 @@ static void allgathervRun(AllgathervOptions& opts) {
   // than the ring below the threshold; the ring wins for bulk payloads
   // where per-link balance matters). Loopback-tuned default; re-sweep on
   // real DCN via TPUCOLL_ALLGATHER_DIRECT_MAX (bytes of total non-local
-  // traffic per rank; BASELINE.md documents the procedure).
+  // traffic per rank).
   static const size_t directMax =
       collectives_detail::envBytes("TPUCOLL_ALLGATHER_DIRECT_MAX", 8u << 20);
   if (maxBlock * size_t(size - 1) <= directMax) {
@@ -616,8 +616,8 @@ void allreduce(AllreduceOptions& opts) {
     if (algo == AllreduceAlgorithm::kAuto) {
       // Measured tuning table first (tuning/dispatch.h: per-deployment
       // crossovers elected by tuning::tune and installed identically on
-      // every rank), then the loopback-measured compile-time fallback
-      // (BASELINE.md): recursive doubling (log2 P full-vector rounds;
+      // every rank), then the loopback-measured compile-time fallback:
+      // recursive doubling (log2 P full-vector rounds;
       // non-power-of-2 groups take a pre/post fold) for the
       // alpha-dominated tiny tier, halving-doubling up to ~1 MiB, the
       // pipelined ring beyond. Re-sweep via bench.py --autotune, or move
@@ -910,7 +910,7 @@ void reduce(ReduceOptions& opts) {
   ReduceAlgorithm algo = opts.algorithm;
   if (algo == ReduceAlgorithm::kAuto) {
     // Measured tuning table first, then the loopback-measured fallback
-    // (BASELINE.md reduce-to-root table, r4 re-sweep): the binomial wins
+    // (reduce-to-root sweep, round 4): the binomial wins
     // p50 through ~4 MiB (its log2(P) full-payload rounds ride the eager
     // pipeline well on one host) but its p99 tail is 3-4x WORSE than the
     // ring's from ~1 MiB up (full-payload rounds spike when the
@@ -1069,7 +1069,7 @@ void reduceScatter(ReduceScatterOptions& opts) {
   }
   if (algo == ReduceScatterAlgorithm::kAuto) {
     // Measured tuning table first (keyed by total payload bytes), then
-    // the crossovers measured on loopback P=4/8 (BASELINE.md round 3):
+    // the crossovers measured on loopback P=4/8 (round 3):
     // recursive halving wins through ~256K, the ring beyond. The
     // single-round direct exchange loses on a shared-core loopback
     // (its P*(P-1) total messages cost more than its one-round latency

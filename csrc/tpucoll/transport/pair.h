@@ -418,7 +418,7 @@ class Pair : public Handler {
   // accumulator right after its AEAD tag verifies, while it is still
   // cache-hot — the whole-message fold at completion would re-read the
   // stage cold, one full memory traversal per byte (measured on the
-  // 16 MiB encrypted-allreduce A/B, BASELINE.md r5). Only verified
+  // 16 MiB encrypted-allreduce A/B, round 5). Only verified
   // plaintext is ever folded; a tampered later frame poisons the pair
   // and the pending op errors out with the accumulator partially
   // updated — same contents-undefined-on-error contract as every other

@@ -13,10 +13,6 @@ Two data planes, mirroring the reference's tcp-vs-ibverbs/CUDA split
   ICI, plus Pallas ring kernels for custom schedules.
 """
 
-# NOTE: gloo_tpu._jaxcompat (the old-jax API backfill) is deliberately
-# NOT imported here — it would drag the multi-second jax import into
-# every host-plane-only process. The device-plane packages
-# (gloo_tpu.tpu / .ops / .parallel / .models) import it themselves.
 from gloo_tpu import elastic, fault, schedule, tuning
 from gloo_tpu.bootstrap import detect_launch_env, init_from_env
 from gloo_tpu.bucketer import GradientBucketer

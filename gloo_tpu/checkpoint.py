@@ -16,11 +16,10 @@ state; sharded state restores onto whatever shardings the template
 carries, so a post-failure SMALLER mesh re-lays the arrays out
 automatically — orbax resharding on restore).
 
-Note for host-plane-only trainer processes: orbax imports jax, whose
-first backend initialization follows the environment's platform pinning;
-processes that do not need an accelerator should force the CPU platform
-(jax.config.update("jax_platforms", "cpu")) before constructing a
-StepCheckpointer to avoid paying accelerator plugin startup per worker.
+Note for host-plane-only trainer processes: orbax imports jax, and jax's
+first backend initialization takes the accelerator if there is one. A
+process that needs none runs with JAX_PLATFORMS=cpu, so that it neither
+waits for nor holds a chip.
 """
 
 from __future__ import annotations

@@ -5,13 +5,6 @@ exist for the same reason gloo's examples and benchmark workloads do: to
 prove the collective layer under a real training loop (DDP gradient sync,
 tensor-parallel matmuls, pipeline-ish shifts)."""
 
-# Backfill renamed jax APIs (jax.shard_map, lax.axis_size, lax.pcast, ...)
-# on old jax releases before any device-plane module touches them;
-# no-op on modern jax. Kept out of the top-level gloo_tpu __init__ so
-# host-plane-only processes never pay the jax import.
-from gloo_tpu import _jaxcompat  # noqa: F401
-
-
 from gloo_tpu.models.mlp import MLP
 from gloo_tpu.models.transformer import Transformer, TransformerConfig
 

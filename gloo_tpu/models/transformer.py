@@ -131,7 +131,7 @@ class Transformer:
         if cfg.use_flash_attention and t % 8 == 0:
             from gloo_tpu.ops.attention import flash_attention
 
-            # Adaptive tile defaults (BASELINE.md block sweep); CPU
+            # Adaptive tile defaults (largest_block); CPU
             # backends only run Pallas through the interpreter.
             out = flash_attention(
                 q, k, v, causal=True,

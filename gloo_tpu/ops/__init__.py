@@ -6,13 +6,6 @@ gloo_tpu.tpu.spmd) are the "NCCL path", these kernels drive the inter-chip
 DMA engines directly for schedules XLA does not emit.
 """
 
-# Backfill renamed jax APIs (jax.shard_map, lax.axis_size, lax.pcast, ...)
-# on old jax releases before any device-plane module touches them;
-# no-op on modern jax. Kept out of the top-level gloo_tpu __init__ so
-# host-plane-only processes never pay the jax import.
-from gloo_tpu import _jaxcompat  # noqa: F401
-
-
 from gloo_tpu.ops.attention import (flash_attention, flash_attention_step,
                                     flash_attention_bwd_step,
                                      largest_block)

@@ -163,9 +163,8 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = None,
 
     block_q/block_k default to the largest divisors of seq up to
     1024/1024: the kernel's cost is dominated by per-grid-step overhead,
-    not the matmuls, so big tiles win — the stable-timing v5e block sweep
-    (BASELINE.md) has 1024x1024 at 77-131 TFLOP/s across t=1k..16k vs
-    ~15 for the round-1 128x128 tiles.
+    not the matmuls, so big tiles win (an early v5e block sweep; its
+    numbers predate the current kernel and are not a measurement of it).
 
     Supports grouped-query attention: k/v may carry h_kv heads with
     h % h_kv == 0. Both directions map each query head to its shared kv
@@ -176,7 +175,14 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = None,
     Differentiable with flash-memory in BOTH directions: the custom VJP
     runs dedicated backward kernels (dQ; dK/dV) that recompute the
     softmax tiles from the saved logsumexp rows — no (T, T)
-    materialization anywhere in training."""
+    materialization anywhere in training.
+
+    Inside shard_map the outputs vary over vma_axes; by default those are
+    the axes q, k and v vary over, so a caller such as a model under
+    make_ddp_train_step need not name them."""
+    if not vma_axes:
+        vma_axes = tuple(sorted(jax.typeof(q).vma | jax.typeof(k).vma
+                                | jax.typeof(v).vma))
     b, h, t, d = q.shape
     h_kv = k.shape[1]
     if v.shape[1] != h_kv:

@@ -446,9 +446,9 @@ def _ring_allreduce_hbm_shard(x, *, axis_name: str, collective_id: int,
             jax.ShapeDtypeStruct((2, chunk_rows, cols), x.dtype,
                                  vma=frozenset({axis_name})),
         ),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],   # stays in HBM
-        out_specs=(pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY)),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],   # stays in HBM
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)),
         scratch_shapes=[
             pltpu.VMEM((2, tile_rows, cols), x.dtype),     # acc tiles (x2)
             pltpu.VMEM((2, tile_rows, cols), x.dtype),     # in tiles (x2)

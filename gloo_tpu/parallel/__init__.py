@@ -20,13 +20,6 @@ by shipping the strategies themselves, each built on a gloo_tpu plane:
   reduce-scatter.
 """
 
-# Backfill renamed jax APIs (jax.shard_map, lax.axis_size, lax.pcast, ...)
-# on old jax releases before any device-plane module touches them;
-# no-op on modern jax. Kept out of the top-level gloo_tpu __init__ so
-# host-plane-only processes never pay the jax import.
-from gloo_tpu import _jaxcompat  # noqa: F401
-
-
 from gloo_tpu.parallel.ddp import HostGradSync, make_ddp_train_step
 from gloo_tpu.parallel.ep import dispatch_combine
 from gloo_tpu.parallel.fsdp import (make_fsdp_train_step, shard_params,

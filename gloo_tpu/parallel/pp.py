@@ -235,7 +235,7 @@ def pipeline_train_1f1b(stage_fn: Callable, loss_fn: Callable,
         # Idempotent: zeros_like of the (already device-varying) stage
         # params is born varying; only fresh replicated zeros need the
         # cast for stable scan carry types under shard_map vma checking.
-        if axis in getattr(jax.typeof(x), "vma", ()):
+        if axis in jax.typeof(x).vma:
             return x
         return lax.pcast(x, (axis,), to="varying")
 

@@ -230,8 +230,6 @@ def ulysses_attention(q, k, v, axis: str, causal: bool = True,
     if h % n != 0:
         raise ValueError(f"heads {h} not divisible by group size {n}")
     if attn_fn is None:
-        import jax
-
         from gloo_tpu.ops.attention import flash_attention
 
         # CPU backends only run Pallas through the interpreter (the

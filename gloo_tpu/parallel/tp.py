@@ -79,11 +79,10 @@ def allgather_matmul_dense(x_rows_shard, w, axis: str,
 # Shape-aware fused/unfused dispatch (r5).
 #
 # The fused overlap kernels hide the TP collective entirely but pay a
-# chunking cost on the matmul itself; measured on a real v5e chip
-# (BASELINE.md "End-to-end fused-TP" + the r4 overlap sweeps) the cost
-# tracks the kernel's shape family: near-parity with >=512-row chunks
-# and K<=2048, but down to 0.68x of the plain-dot step at 256-row
-# chunks with K=4096. Whether fusing wins therefore depends on how much
+# chunking cost on the matmul itself. An early single-chip v5e reading
+# (round 4, not reproduced on the current code) had the cost track the
+# kernel's shape family: near-parity with >=512-row chunks and K<=2048,
+# but down to 0.68x of the plain-dot step at 256-row chunks with K=4096. Whether fusing wins therefore depends on how much
 # of the unfused step the collective would cost: with ratio = fused
 # compute throughput / plain-dot throughput and share = collective time
 # / unfused step time, fused wins iff share > 1 - ratio. Encoding that
@@ -165,8 +164,8 @@ def use_fused_overlap(m: int, k: int, cols: int, axis_size: int,
     otherwise it is estimated from shape + hardware parameters. Pass
     `ratio` from measure_fused_ratio() to use THIS process's measured
     compile draw instead of the shape model (the fused kernels'
-    throughput is bimodal across compiles on some shapes — BASELINE.md
-    "Overlap kernels" — and a measured slow draw should fall back to
+    throughput was once seen bimodal across compiles on some shapes, and
+    a measured slow draw should fall back to
     unfused even where the model would fuse).
     TPUCOLL_TP_OVERLAP=fused|unfused forces either way (auto/unset =
     decide); anything else raises.
@@ -205,10 +204,9 @@ def measure_fused_ratio(m: int, k: int, axis_size: int,
     schedule with the ICI leg replaced by on-chip DMA — identical
     compute pipeline, no other participants needed).
 
-    Why measure instead of model: the fused kernels' throughput is
-    BIMODAL across process restarts on some shapes (fast ~0.88x of
-    plain, slow ~0.79x at 2048x4096 — BASELINE.md); the shape model
-    cannot know which draw this process got, a probe can. Feed the
+    Why measure instead of model: the fused kernels' throughput was
+    once seen bimodal across process restarts on some shapes; the shape
+    model cannot know which draw this process got, a probe can. Feed the
     result to use_fused_overlap(ratio=...) — a slow draw then falls
     back to plain dots + explicit collectives.
 
@@ -223,8 +221,8 @@ def measure_fused_ratio(m: int, k: int, axis_size: int,
     you need certainty.
 
     The probe runs the square [m, k] @ [k, k] member of the shape
-    family — the measured penalty tracks (chunk rows, K), not the
-    output width (BASELINE.md r4 sweeps), and the square output chains
+    family — the penalty tracked (chunk rows, K), not the output width,
+    in the round-4 sweeps, and the square output chains
     back into the timing loop. Cost: one extra compile of the
     self-loop kernel (minutes for unrolled rings on TPU — comparable
     to the training step's own compile) plus ~chain*reps kernel

@@ -16,19 +16,14 @@ Two usage levels:
   and every call is a compiled XLA program.
 """
 
-# Backfill renamed jax APIs (jax.shard_map, lax.axis_size, lax.pcast, ...)
-# on old jax releases before any device-plane module touches them;
-# no-op on modern jax. Kept out of the top-level gloo_tpu __init__ so
-# host-plane-only processes never pay the jax import.
-from gloo_tpu import _jaxcompat  # noqa: F401
-
-
 from gloo_tpu.tpu import spmd
+from gloo_tpu.tpu.compile_cache import enable_compile_cache
 from gloo_tpu.tpu.group import TpuProcessGroup
 from gloo_tpu.tpu.hierarchical import (HierarchicalGroup,
                                        make_hierarchical_ddp)
 from gloo_tpu.tpu.mesh import make_mesh
 from gloo_tpu.tpu.multihost import init_multihost
 
-__all__ = ["HierarchicalGroup", "TpuProcessGroup", "init_multihost",
+__all__ = ["HierarchicalGroup", "TpuProcessGroup", "enable_compile_cache",
+           "init_multihost",
            "make_hierarchical_ddp", "make_mesh", "spmd"]

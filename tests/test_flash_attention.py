@@ -236,8 +236,7 @@ def test_flash_large_square_tiles_match(t, bq):
     short-sequence serving configuration). Guards the diagonal-tile
     masked path at realistic tile sizes; r4 note: a strip-mined
     diagonal-tile variant was measured 2.1x SLOWER on v5e (thin strip
-    matmuls + serialized online-softmax chains) and reverted — see
-    BASELINE.md "flash short-sequence floor"."""
+    matmuls + serialized online-softmax chains) and reverted."""
     rng = np.random.RandomState(5)
     b, h, d = 1, 2, 128
     q = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)

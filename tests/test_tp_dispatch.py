@@ -1,6 +1,6 @@
 """Shape-aware fused/unfused TP dispatch (gloo_tpu/parallel/tp.py r5).
 
-Pins the deployment rule from BASELINE.md "End-to-end fused-TP" in code:
+Pins the deployment rule in code:
 fused wins iff the collective's share of the unfused step exceeds the
 fused kernels' measured compute penalty (share > 1 - ratio). The two
 measured shape families are the calibration points — M=4096/K=2048
@@ -26,8 +26,9 @@ V = 8  # ring size of the measured calibration points
 
 def test_ratio_matches_measured_families():
     """The ratio model reproduces the two end-to-end measurements
-    (BASELINE.md: 0.93 at M=4096/K=2048, 0.68 at M=2048/K=4096) within
-    a few points, conservative side."""
+    it was calibrated on (0.93 at M=4096/K=2048, 0.68 at M=2048/K=4096;
+    round-4 single-chip readings, not reproduced on the current code)
+    within a few points, conservative side."""
     fast = fused_compute_ratio(4096, 2048, V)   # 512-row chunks, K=2048
     slow = fused_compute_ratio(2048, 4096, V)   # 256-row chunks, K=4096
     assert abs(fast - 0.93) < 0.05, fast

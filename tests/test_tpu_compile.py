@@ -1,0 +1,115 @@
+"""Compile the main path's kernels and step for a described TPU v5e.
+
+Nothing runs: the TPU compiler installed here compiles for a v5e:2x2 that
+is described, not attached, and refuses what the chip would refuse
+(misaligned tiles, too much VMEM, a program larger than HBM). Sizes are
+chip_smoke.py's. The topology is described inside a fixture, never while a
+module is imported: only one process at a time may load libtpu, and every
+xdist worker imports this file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+import chip_smoke
+
+HBM_BYTES = 16 * 2**30  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it.
+    saved = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", saved)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.asarray(topo.devices[:4], dtype=object), ("data",))
+
+
+def _check(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB per chip"
+
+
+def test_flash_attention_fwd_bwd(one_chip):
+    from gloo_tpu.ops import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    x = jax.ShapeDtypeStruct((1, 12, 1024, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    _check(jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x)
+           .compile())
+
+
+@pytest.mark.parametrize("name", ["ring_allreduce", "ring_allreduce_hbm",
+                                  "ring_reduce_scatter", "ring_allgather",
+                                  "ring_allreduce_q8", "pallas_alltoall"])
+def test_ring_four_chips(mesh4, name):
+    n = mesh4.size
+    cases = {c[0]: c for c in chip_smoke._ring_cases(n, interpret=False)}
+    _, pallas, _, rows, _ = cases[name]
+    fn = jax.jit(jax.shard_map(pallas, mesh=mesh4, in_specs=P("data"),
+                               out_specs=P("data"), check_vma=False))
+    x = jax.ShapeDtypeStruct((n * rows, chip_smoke.RING_COLS), jnp.float32,
+                             sharding=NamedSharding(mesh4, P("data")))
+    _check(fn.lower(x).compile())
+
+
+def test_gpt2_small_ddp_step(topo, monkeypatch):
+    """chip_smoke's training step on one described chip. The model picks
+    the Pallas interpreter when jax.default_backend() is "cpu", which it
+    is here: steer it to the compiled kernel for this test only."""
+    import optax
+
+    from gloo_tpu.models import Transformer, TransformerConfig
+    from gloo_tpu.parallel import make_ddp_train_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.asarray(topo.devices[:1], dtype=object), ("data",))
+    cfg = TransformerConfig(**chip_smoke.GPT2_SMALL,
+                            use_flash_attention=True)
+    model = Transformer(cfg)
+    opt = optax.adamw(chip_smoke.LR)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    opt_state = jax.eval_shape(opt.init, params)
+
+    def placed(tree, spec):
+        sharding = NamedSharding(mesh, spec)
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sharding), tree)
+
+    batch = jax.ShapeDtypeStruct((8, cfg.max_seq_len), jnp.int32)
+    step = make_ddp_train_step(model.loss, opt, mesh)
+    _check(step.lower(placed(params, P()), placed(opt_state, P()),
+                      placed((batch, batch), P("data"))).compile())

@@ -7,8 +7,8 @@ artifact: every cell of workload x payload x ranks x payload-plane
 {plain TCP, shm, encrypted} x event engine {epoll, uring} measured with
 the SAME multi-process methodology (FileStore rendezvous, one OS
 process per rank — the deployment shape, not the thread harness), so
-BASELINE.md tables can cite committed JSON instead of hand-transcribed
-prose, and round-over-round regressions are a `diff` away.
+notes can cite committed JSON instead of hand-transcribed prose, and
+round-over-round regressions are a `diff` away.
 
 Usage: python tools/bench_sweep.py [--quick] [--out BASELINE_sweep.json]
 Each cell records p50/p99/min latency (us), algorithm bandwidth at p50,
@@ -103,8 +103,7 @@ def main():
                     help="repetitions per cell; >1 records the "
                          "median-p50 rep (plus every rep's p50) so a "
                          "single scheduler transient cannot fabricate a "
-                         "3x regression — the r5 sweep hit exactly that "
-                         "(BASELINE.md 'r5 regression sweep')")
+                         "3x regression — the r5 sweep hit exactly that")
     args = ap.parse_args()
     if args.reps < 1:
         ap.error("--reps must be >= 1")
@@ -180,7 +179,7 @@ def main():
                        f"min-time {min_time}s per cell; "
                        f"reps {args.reps} (lower-median-p50 rep kept)",
         "reps": args.reps,
-        "host": "single shared core (BASELINE.md: +/-15% run-to-run); "
+        "host": "single shared core (+/-15% run-to-run); "
                 "treat cross-cell ratios, not absolutes, as the signal",
         "timestamp_unix": int(t0),
         "cells": cells,
