@@ -33,15 +33,18 @@ def make_ddp_train_step(loss_fn: Callable, optimizer, mesh,
     """
 
     def local_grads(params, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        # Forward ops carry `gloo_tpu.ddp.loss/jvp()` in their HLO op_name,
+        # backward ops `gloo_tpu.ddp.loss/transpose(jvp())`.
+        with jax.named_scope("gloo_tpu.ddp.loss"):
+            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         # Params enter the manual region replicated, so AD's transpose has
-        # already psum'd the per-device gradients across `axis`; dividing by
+        # already psum'd the per-device gradients across `axis` (the
+        # all-reduce is `.../transpose(jvp())/psum_invariant`); dividing by
         # the axis size yields the mean (adding a pmean here would be a
         # no-op on the already-replicated value, not a division).
-        with jax.named_scope("gloo_tpu.ddp.grad_sync"):
-            n = spmd.size(axis)
-            grads = jax.tree.map(lambda g: g / n, grads)
-            return spmd.mean(loss, axis), grads
+        n = spmd.size(axis)
+        grads = jax.tree.map(lambda g: g / n, grads)
+        return spmd.mean(loss, axis), grads
 
     import optax
 
@@ -53,8 +56,9 @@ def make_ddp_train_step(loss_fn: Callable, optimizer, mesh,
     @jax.jit
     def step(params, opt_state, batch):
         loss, grads = sharded_grads(params, batch)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("gloo_tpu.ddp.optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     return step

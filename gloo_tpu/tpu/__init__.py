@@ -21,9 +21,10 @@ from gloo_tpu.tpu.compile_cache import enable_compile_cache
 from gloo_tpu.tpu.group import TpuProcessGroup
 from gloo_tpu.tpu.hierarchical import (HierarchicalGroup,
                                        make_hierarchical_ddp)
+from gloo_tpu.tpu.hlo_stats import CollectiveStats, collective_stats
 from gloo_tpu.tpu.mesh import make_mesh
 from gloo_tpu.tpu.multihost import init_multihost
 
-__all__ = ["HierarchicalGroup", "TpuProcessGroup", "enable_compile_cache",
-           "init_multihost",
+__all__ = ["CollectiveStats", "HierarchicalGroup", "TpuProcessGroup",
+           "collective_stats", "enable_compile_cache", "init_multihost",
            "make_hierarchical_ddp", "make_mesh", "spmd"]

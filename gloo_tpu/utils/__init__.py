@@ -5,13 +5,12 @@ from gloo_tpu.utils.flightrec import DesyncError
 from gloo_tpu.utils.metrics import (histogram_quantile, merge_snapshots,
                                     summarize_ops, to_prometheus)
 from gloo_tpu.utils.telemetry import TelemetryServer, serve_telemetry
-from gloo_tpu.utils.tracing import annotate, device_trace, merge_traces
+from gloo_tpu.utils.tracing import annotate, merge_traces
 
 __all__ = [
     "DesyncError",
     "TelemetryServer",
     "annotate",
-    "device_trace",
     "fleet",
     "flightrec",
     "histogram_quantile",

@@ -1,10 +1,11 @@
 """Tracing utilities spanning both data planes.
 
 Host plane: `Context.trace_start()/trace_json()` records collective spans
-in the C++ core (Chrome trace-event format). Device plane: `device_trace`
-wraps the XLA/jax profiler so compiled collectives over the mesh are
-captured in the same investigation (view in TensorBoard / Perfetto).
-`merge_traces` combines per-rank host traces into one timeline.
+in the C++ core (Chrome trace-event format). Device plane:
+`jax.profiler.trace(logdir)` captures compiled collectives over the mesh
+in the same investigation (view in TensorBoard / Perfetto); `annotate`
+puts host-plane work on that timeline. `merge_traces` combines per-rank
+host traces into one timeline.
 """
 
 from __future__ import annotations
@@ -12,19 +13,6 @@ from __future__ import annotations
 import contextlib
 import json
 from typing import Iterable
-
-
-@contextlib.contextmanager
-def device_trace(logdir: str):
-    """Profile the device plane (XLA execution, ICI collectives) into
-    `logdir`; open with TensorBoard's profile plugin or Perfetto."""
-    import jax
-
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
 
 
 @contextlib.contextmanager

@@ -113,3 +113,41 @@ def test_gpt2_small_ddp_step(topo, monkeypatch):
     step = make_ddp_train_step(model.loss, opt, mesh)
     _check(step.lower(placed(params, P()), placed(opt_state, P()),
                       placed((batch, batch), P("data"))).compile())
+
+
+def test_gpt2_small_ddp_step_sends(topo, monkeypatch):
+    """What the four-chip GPT-2-small DDP step hands its all-reduces on
+    each chip, counted by `collective_stats` from the compiled program:
+    the gradient of every param once, the embedding's and the learned
+    positions' in f32, every other param's in bf16 (the layers cast them
+    before use, and AD's transpose of the cast is where the psum lands),
+    and the loss's f32 scalar."""
+    import optax
+
+    from gloo_tpu.models import Transformer, TransformerConfig
+    from gloo_tpu.parallel import make_ddp_train_step
+    from gloo_tpu.tpu import collective_stats
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.asarray(topo.devices[:4], dtype=object), ("data",))
+    model = Transformer(TransformerConfig(**chip_smoke.GPT2_SMALL,
+                                          use_flash_attention=True))
+    opt = optax.adamw(chip_smoke.LR)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+
+    def placed(tree, spec):
+        sharding = NamedSharding(mesh, spec)
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sharding), tree)
+
+    batch = jax.ShapeDtypeStruct((8, 1024), jnp.int32)
+    step = make_ddp_train_step(model.loss, opt, mesh)
+    stats = collective_stats(step.lower(
+        placed(params, P()), placed(jax.eval_shape(opt.init, params), P()),
+        placed((batch, batch), P("data"))).compile())
+    f32 = params["embed"].size + params["pos"].size
+    bf16 = sum(x.size for x in jax.tree.leaves(params)) - f32
+    assert stats.dtypes == {"all-reduce": {"f32": 4 * f32 + 4,
+                                           "bf16": 2 * bf16}}
+    assert stats.bytes["all-reduce"] == 327_587_332
+    assert stats.calls["all-reduce"] == 4
