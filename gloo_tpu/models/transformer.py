@@ -172,13 +172,16 @@ class Transformer:
         return (x.astype(jnp.float32) @ params["embed"].T)
 
     def loss(self, params, batch):
-        """batch: (tokens, targets), each (batch, seq) int32."""
+        """batch: (tokens, targets), each (batch, seq) int32.
+
+        Each token's NLL is logsumexp(logits) - logits[target]: the same
+        value as -log_softmax(logits)[target], without a second vocab-wide
+        f32 tensor for the step to write and read back."""
         tokens, targets = batch
         logits = self.apply(params, tokens)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None],
-                                   axis=-1).squeeze(-1)
-        return jnp.mean(nll)
+        target_logit = jnp.take_along_axis(logits, targets[..., None],
+                                           axis=-1).squeeze(-1)
+        return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - target_logit)
 
     # ---- incremental decoding (KV cache) ----
 

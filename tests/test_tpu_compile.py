@@ -9,6 +9,7 @@ xdist worker imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -86,16 +87,17 @@ def test_ring_four_chips(mesh4, name):
     _check(fn.lower(x).compile())
 
 
-def test_gpt2_small_ddp_step(topo, monkeypatch):
-    """chip_smoke's training step on one described chip. The model picks
-    the Pallas interpreter when jax.default_backend() is "cpu", which it
-    is here: steer it to the compiled kernel for this test only."""
+@pytest.fixture(scope="module")
+def gpt2_small_step(topo):
+    """chip_smoke's training step, compiled for one described chip. The
+    model picks the Pallas interpreter when jax.default_backend() is
+    "cpu", which it is here: steer it to the compiled kernel while the
+    step compiles."""
     import optax
 
     from gloo_tpu.models import Transformer, TransformerConfig
     from gloo_tpu.parallel import make_ddp_train_step
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = Mesh(np.asarray(topo.devices[:1], dtype=object), ("data",))
     cfg = TransformerConfig(**chip_smoke.GPT2_SMALL,
                             use_flash_attention=True)
@@ -111,8 +113,29 @@ def test_gpt2_small_ddp_step(topo, monkeypatch):
 
     batch = jax.ShapeDtypeStruct((8, cfg.max_seq_len), jnp.int32)
     step = make_ddp_train_step(model.loss, opt, mesh)
-    _check(step.lower(placed(params, P()), placed(opt_state, P()),
-                      placed((batch, batch), P("data"))).compile())
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        return step.lower(placed(params, P()), placed(opt_state, P()),
+                          placed((batch, batch), P("data"))).compile()
+
+
+def test_gpt2_small_ddp_step(gpt2_small_step):
+    _check(gpt2_small_step)
+
+
+def test_gpt2_small_loss_writes_logits_once(gpt2_small_step):
+    """The loss reads the f32 logits [8, 1024, vocab] and writes no second
+    tensor of that size: of the top-level fusions, only the logits matmul
+    outputs one. A loss taken from a materialized log_softmax adds a
+    second, and a backward pass that reduces over it."""
+    text = gpt2_small_step.as_text()
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    vocab = chip_smoke.GPT2_SMALL["vocab_size"]
+    wide = [m[1] for m in re.finditer(
+        r"^\s*(?:ROOT\s+)?%(\S+) = (.*?) fusion\(", entry, re.MULTILINE)
+        if f"f32[8,1024,{vocab}]" in m[2]]
+    assert len(wide) == 1, wide
 
 
 def test_gpt2_small_ddp_step_sends(topo, monkeypatch):
