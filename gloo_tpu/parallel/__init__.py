@@ -13,15 +13,15 @@ by shipping the strategies themselves, each built on a gloo_tpu plane:
 - `pp`: pipeline parallelism — the GPipe forward schedule plus the
   1F1B training schedule (activation stash bounded by stages, not
   microbatches), both static timetables under one lax.scan;
-- `ep`: expert parallelism — fixed-capacity MoE dispatch/combine over
-  all_to_all;
+- `ep`: expert parallelism — a dropless MoE layer whose tokens reach the
+  chips holding their experts over a ragged all-to-all;
 - `fsdp`: ZeRO-3-style fully-sharded data parallelism — just-in-time
   parameter allgather whose autodiff transpose is the gradient
   reduce-scatter.
 """
 
 from gloo_tpu.parallel.ddp import HostGradSync, make_ddp_train_step
-from gloo_tpu.parallel.ep import dispatch_combine
+from gloo_tpu.parallel.ep import moe
 from gloo_tpu.parallel.fsdp import (make_fsdp_train_step, shard_params,
                                     unshard_params)
 from gloo_tpu.parallel.pp import pipeline_apply, pipeline_train_1f1b
@@ -38,7 +38,6 @@ __all__ = [
     "HostGradSync",
     "allgather_matmul_dense_auto",
     "column_parallel_dense",
-    "dispatch_combine",
     "estimate_comm_share",
     "fused_compute_ratio",
     "measure_fused_ratio",
@@ -46,6 +45,7 @@ __all__ = [
     "use_fused_overlap",
     "make_ddp_train_step",
     "make_fsdp_train_step",
+    "moe",
     "pipeline_apply",
     "pipeline_train_1f1b",
     "ring_attention",

@@ -3,7 +3,8 @@
 Device plane: `make_ddp_train_step` compiles one XLA program where the
 batch is sharded over the mesh's data axis, gradients are psum-averaged
 over ICI inside shard_map, and the optimizer runs replicated — the
-standard TPU DDP recipe.
+standard TPU DDP recipe. Leaves named in `param_specs` may instead be
+split over the axis (expert parallelism's experts, one block a chip).
 
 Host plane: `HostGradSync` averages numpy gradient pytrees across OS
 processes with the C++ allreduce — exactly the role the reference plays as
@@ -24,24 +25,34 @@ from gloo_tpu.tpu import spmd
 
 
 def make_ddp_train_step(loss_fn: Callable, optimizer, mesh,
-                        axis: str = "data"):
+                        axis: str = "data", param_specs=None):
     """Build a jitted (params, opt_state, batch) -> (params, opt_state,
     loss) step with gradient averaging over `axis`.
 
     `loss_fn(params, batch)` consumes the per-device micro-batch; `batch`
     leaves must have a leading axis divisible by the axis size.
+
+    `param_specs`: a pytree of PartitionSpec like params, `P()` (every
+    leaf replicated) by default. A leaf on `P(axis)` enters and leaves the
+    step split over `axis`, each chip with its own slice (expert weights
+    under expert parallelism); its gradient reaches it through the
+    transpose of the exchange that fed it, not through an all-reduce, and
+    its optimizer state stays split the same way.
     """
+    specs = P() if param_specs is None else param_specs
 
     def local_grads(params, batch):
         # Forward ops carry `gloo_tpu.ddp.loss/jvp()` in their HLO op_name,
         # backward ops `gloo_tpu.ddp.loss/transpose(jvp())`.
         with jax.named_scope("gloo_tpu.ddp.loss"):
             loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        # Params enter the manual region replicated, so AD's transpose has
-        # already psum'd the per-device gradients across `axis` (the
-        # all-reduce is `.../transpose(jvp())/psum_invariant`); dividing by
-        # the axis size yields the mean (adding a pmean here would be a
-        # no-op on the already-replicated value, not a division).
+        # Replicated params enter the manual region invariant, so AD's
+        # transpose has already psum'd the per-device gradients across
+        # `axis` (the all-reduce is `.../transpose(jvp())/psum_invariant`);
+        # a split leaf's gradient is likewise every chip's contribution,
+        # summed by the exchange's transpose. Dividing by the axis size
+        # yields the mean for both (adding a pmean here would be a no-op
+        # on the already-replicated value, not a division).
         n = spmd.size(axis)
         grads = jax.tree.map(lambda g: g / n, grads)
         return spmd.mean(loss, axis), grads
@@ -50,8 +61,8 @@ def make_ddp_train_step(loss_fn: Callable, optimizer, mesh,
 
     sharded_grads = jax.shard_map(
         local_grads, mesh=mesh,
-        in_specs=(P(), P(axis)),
-        out_specs=(P(), P()))
+        in_specs=(specs, P(axis)),
+        out_specs=(P(), specs))
 
     @jax.jit
     def step(params, opt_state, batch):

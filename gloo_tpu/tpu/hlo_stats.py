@@ -14,7 +14,9 @@ from many gradients counts as one call carrying all of their bytes.
     stats.dtypes["all-reduce"]           # {"f32": bytes, "bf16": bytes}
     stats.op_names["psum_invariant.616"]  # the instruction's op_name
 
-Counts are static: a collective inside a loop body counts once.
+Counts are static: a collective inside a loop body counts once, and a
+`ragged-all-to-all` counts its whole operand and output buffers (sized
+for the worst case), not the rows a run sends.
 """
 
 from __future__ import annotations
@@ -25,7 +27,7 @@ from dataclasses import dataclass, field
 # Synchronous collectives and the start half of their async pairs; the
 # `-done` half carries no bytes of its own.
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
-               "collective-permute")
+               "ragged-all-to-all", "collective-permute")
 OPCODES = COLLECTIVES + tuple(op + "-start" for op in COLLECTIVES)
 
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (.*)$")

@@ -86,6 +86,105 @@ def alltoall(x, axis: Axis, split_axis: int = 0, concat_axis: int = 0):
                               concat_axis=concat_axis, tiled=True)
 
 
+def ragged_alltoall(rows, send_counts, axis: Axis, out_rows: int = None):
+    """Send each destination only the rows meant for it: no capacity, no
+    padding on the wire. The counts are exchanged first, then the rows.
+
+    rows: (R, ...) grouped by destination along `axis`, and within a
+    destination by slot (an expert, say); rows past the counts' sum are
+    not sent. send_counts: (n, s) integer rows for each destination's s
+    slots. Returns (received, recv_counts): `received` is (out_rows, ...),
+    slot-major with the sources in axis order inside a slot, zero past
+    the rows that came; recv_counts[i, l] is the rows from source i for
+    slot l. out_rows defaults to n * R, the most that can arrive.
+    Differentiable: the gradient is the reverse exchange."""
+    n = size(axis)
+    out_rows = n * rows.shape[0] if out_rows is None else out_rows
+    with jax.named_scope("gloo_tpu.ragged_alltoall"):
+        counts = _all_counts(send_counts, axis)
+        me = rank(axis)
+        sent = _flat_starts(counts[me]).reshape(-1)
+        land = _slot_starts(counts) + _source_starts(counts)
+        recv = counts[:, me]
+        received = _ragged_exchange(rows, out_rows, sent,
+                                    counts[me].reshape(-1),
+                                    land[me].reshape(-1), recv.reshape(-1),
+                                    axis)
+        return received, recv
+
+
+def ragged_alltoall_reverse(received, send_counts, axis: Axis,
+                            out_rows: int):
+    """The inverse of `ragged_alltoall(rows, send_counts, axis)`: every
+    received row goes back to where it came from. `received` is laid out
+    as that call returned it and `send_counts` is what the caller gave it;
+    returns (out_rows, ...) in the original senders' row order, zero past
+    the rows they sent."""
+    with jax.named_scope("gloo_tpu.ragged_alltoall"):
+        counts = _all_counts(send_counts, axis)
+        me = rank(axis)
+        land = _slot_starts(counts) + _source_starts(counts)
+        back = jax.vmap(_flat_starts)(counts)           # (src, dst, slot)
+        return _ragged_exchange(received, out_rows, land[:, me].reshape(-1),
+                                counts[:, me].reshape(-1),
+                                back[:, me].reshape(-1),
+                                counts[me].reshape(-1), axis)
+
+
+def _all_counts(send_counts, axis):
+    """(sources, destinations, slots) int32: every chip's send counts."""
+    return lax.all_gather(send_counts.astype(jnp.int32), axis)
+
+
+def _flat_starts(counts):
+    """Exclusive running sum over (destination, slot), row-major."""
+    flat = counts.reshape(-1)
+    return (jnp.cumsum(flat) - flat).reshape(counts.shape)
+
+
+def _slot_starts(counts):
+    """(dst, slot): where each slot's rows begin on each receiver, the
+    slots in order, every source's rows for a slot together."""
+    per_slot = counts.sum(axis=0)                       # (dst, slot)
+    return (jnp.cumsum(per_slot, axis=1) - per_slot)[None]
+
+
+def _source_starts(counts):
+    """(src, dst, slot): each source's place inside a slot, by axis
+    position."""
+    return jnp.cumsum(counts, axis=0) - counts
+
+
+def _ragged_exchange(operand, out_rows, input_offsets, send_sizes,
+                     output_offsets, recv_sizes, axis):
+    """`lax.ragged_all_to_all` into zeros where the backend has the op;
+    elsewhere (XLA:CPU has none) an exact emulation over `all_to_all`."""
+    out = jnp.zeros((out_rows,) + operand.shape[1:], operand.dtype)
+    args = [x.astype(jnp.int32) for x in (input_offsets, send_sizes,
+                                          output_offsets, recv_sizes)]
+    if jax.default_backend() != "cpu":
+        if axis in jax.typeof(operand).vma:
+            # The op's result takes `out`'s type: varying, as the rows are,
+            # or AD would all-reduce its cotangent across the axis.
+            out = lax.pcast(out, axis, to="varying")
+        return lax.ragged_all_to_all(operand, out, *args, axis_name=axis)
+    input_offsets, send_sizes, output_offsets, recv_sizes = args
+    # Every slice padded to the whole operand, sent whole; the receiver
+    # writes the rows that count where the sender said they go.
+    pad = operand.shape[0]
+    row = jnp.arange(pad, dtype=jnp.int32)
+    keep = row[None] < send_sizes[:, None]
+    idx = jnp.where(keep, input_offsets[:, None] + row[None], 0)
+    blocks = jnp.where(keep.reshape(keep.shape + (1,) * (operand.ndim - 1)),
+                       operand[idx], 0)
+    blocks = lax.all_to_all(blocks, axis, 0, 0, tiled=True)
+    where = lax.all_to_all(output_offsets, axis, 0, 0, tiled=True)
+    dest = jnp.where(row[None] < recv_sizes[:, None],
+                     where[:, None] + row[None], out_rows)
+    return out.at[dest.reshape(-1)].add(
+        blocks.reshape((-1,) + operand.shape[1:]), mode="drop")
+
+
 def broadcast(x, axis: Axis, root: int = 0):
     """Every shard receives the root shard's value."""
     with jax.named_scope("gloo_tpu.broadcast"):

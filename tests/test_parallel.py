@@ -182,49 +182,46 @@ def test_pipeline_parallel_matches_sequential():
     np.testing.assert_allclose(got, expected, rtol=1e-5, atol=1e-6)
 
 
-def test_expert_parallel_dispatch_combine():
-    """MoE routing: every kept token processed by its assigned expert."""
-    from gloo_tpu.parallel import dispatch_combine
+@pytest.mark.parametrize("held", [True, False],
+                         ids=["routed", "unheld_expert"])
+def test_expert_parallel_one_expert_a_device(held):
+    """One expert a device, top-1: each token comes back from the expert
+    its router picked, weighted by the router's score. A token whose
+    expert no device on the axis holds (the router scores twice the
+    experts held) gets zeros, not another expert's output."""
+    from gloo_tpu.parallel import moe
 
     mesh = make_mesh({"expert": -1})
-    n_exp = mesh.shape["expert"]
-    t_local, d, capacity = 16, 8, 16  # capacity ample: nothing dropped
+    n = mesh.shape["expert"]
+    g = 2 * n
+    t_local, f = 8, 4
     rng = np.random.RandomState(9)
-    tokens = rng.randn(n_exp * t_local, d).astype(np.float32)
-    assignment = rng.randint(0, n_exp, n_exp * t_local).astype(np.int32)
-    # Per-expert scale so expert identity is observable.
-    scales = (1.0 + np.arange(n_exp)).astype(np.float32)
+    choice = rng.randint(0, n, n * t_local) + (0 if held else n)
+    x = (np.eye(g, dtype=np.float32)[choice]
+         + 0.01 * rng.randn(n * t_local, g).astype(np.float32))
+    router = (20.0 * np.eye(g)).astype(np.float32)
+    wg, wu = (rng.randn(n, g, f).astype(np.float32) for _ in range(2))
+    wd = rng.randn(n, f, g).astype(np.float32)
 
-    def shard_fn(tok, idx, scale):
-        def expert(x):
-            return x * scale[0]
-        return dispatch_combine(expert, tok, idx, capacity, "expert")
+    def shard_fn(x, router, wg, wu, wd):
+        return moe(x, router, wg, wu, wd, first_expert=jax.lax.axis_index(
+            "expert"), top_k=1, axis="expert")[0]
 
-    f = jax.jit(jax.shard_map(
+    out = np.asarray(jax.jit(jax.shard_map(
         shard_fn, mesh=mesh,
-        in_specs=(P("expert"), P("expert"), P("expert")),
-        out_specs=P("expert")))
-    out = np.asarray(f(tokens, assignment, scales))
-    expected = tokens * scales[assignment][:, None]
-    np.testing.assert_allclose(out, expected, rtol=1e-6)
-
-
-def test_expert_parallel_out_of_range_assignment_dropped():
-    """A router bug producing expert_idx >= n_experts must yield zeros,
-    not another expert's output (regression test)."""
-    from gloo_tpu.parallel import dispatch_combine
-
-    mesh = make_mesh({"expert": -1})
-    n_exp = mesh.shape["expert"]
-    tokens = np.ones((n_exp * 4, 8), np.float32)
-    assignment = np.full(n_exp * 4, n_exp + 3, np.int32)  # all invalid
-
-    f = jax.jit(jax.shard_map(
-        lambda t, i: dispatch_combine(lambda x: x * 2.0, t, i, 8, "expert"),
-        mesh=mesh, in_specs=(P("expert"), P("expert")),
-        out_specs=P("expert")))
-    out = np.asarray(f(tokens, assignment))
-    np.testing.assert_array_equal(out, np.zeros_like(out))
+        in_specs=(P("expert"), P(), P("expert"), P("expert"), P("expert")),
+        out_specs=P("expert")))(x, router, wg, wu, wd))
+    if not held:
+        np.testing.assert_array_equal(out, np.zeros_like(out))
+        return
+    logits = x @ router
+    score = np.exp(logits - logits.max(1, keepdims=True))
+    score = (score / score.sum(1, keepdims=True))[np.arange(len(x)), choice]
+    gate = np.einsum("td,tdf->tf", x, wg[choice])
+    up = np.einsum("td,tdf->tf", x, wu[choice])
+    expected = score[:, None] * np.einsum(
+        "tf,tfd->td", gate / (1 + np.exp(-gate)) * up, wd[choice])
+    np.testing.assert_allclose(out, expected, rtol=1e-4, atol=1e-5)
 
 
 @pytest.mark.parametrize("causal", [True, False])

@@ -174,3 +174,95 @@ def test_gpt2_small_ddp_step_sends(topo, monkeypatch):
                                            "bf16": 2 * bf16}}
     assert stats.bytes["all-reduce"] == 327_587_332
     assert stats.calls["all-reduce"] == 4
+
+
+def test_gpt2_small_step_unchanged_by_explicit_replicated_specs(
+        topo, monkeypatch):
+    """`make_ddp_train_step` with `param_specs` all `P()` compiles the
+    four-chip GPT-2-small step to the same optimized HLO as without it."""
+    import optax
+
+    from gloo_tpu.models import Transformer, TransformerConfig
+    from gloo_tpu.parallel import make_ddp_train_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.asarray(topo.devices[:4], dtype=object), ("data",))
+    model = Transformer(TransformerConfig(**chip_smoke.GPT2_SMALL,
+                                          use_flash_attention=True))
+    opt = optax.adamw(chip_smoke.LR)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+
+    def placed(tree, spec):
+        sharding = NamedSharding(mesh, spec)
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sharding), tree)
+
+    batch = jax.ShapeDtypeStruct((8, 1024), jnp.int32)
+    args = (placed(params, P()), placed(jax.eval_shape(opt.init, params),
+                                        P()),
+            placed((batch, batch), P("data")))
+
+    def hlo(**kw):
+        """The optimized HLO without source locations: the metadata and
+        the stack-frame tables name the lines that built the step."""
+        step = make_ddp_train_step(model.loss, opt, mesh, **kw)
+        text = step.lower(*args).compile().as_text()
+        return [re.sub(r",? metadata=\{[^}]*\}", "", line)
+                for line in text.splitlines() if line[:1] in "H%E }"]
+
+    specs = jax.tree.map(lambda _: P(), params)
+    assert hlo() == hlo(param_specs=specs)
+
+
+DSV2_CHIPS = (1, 4)
+
+
+@pytest.fixture(scope="module")
+def dsv2_steps(topo):
+    """DeepSeek-V2-Lite's step at full size (8192 tokens, 2 rows a chip),
+    through the benchmark's `ep` recipe with the `dsv2l-ep1-s8k` cell's
+    configuration, compiled for the described chips: one chip, and four
+    with experts split 2 a chip."""
+    from benchmark import ep_scopes, harness
+
+    cell = harness.load_cell("dsv2l-ep1-s8k")
+    out = {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        for chips in DSV2_CHIPS:
+            out[chips] = ep_scopes.compile_step(
+                cell.config, dict(cell.traffic, data_parallel=chips),
+                topo.devices[:chips])
+    return out
+
+
+@pytest.mark.parametrize("chips", DSV2_CHIPS)
+def test_dsv2_lite_step_fits(dsv2_steps, chips):
+    """The flash kernel is in; inputs, temporaries and the outputs that do
+    not reuse a donated input fit one chip's memory, and the params and
+    AdamW state are donated (a step in flight holds one copy of them)."""
+    compiled = dsv2_steps[chips]
+    _check(compiled)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes > 0.99 * mem.argument_size_in_bytes
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB per chip"
+
+
+def test_dsv2_lite_exchange_is_ragged_and_only_on_four_chips(dsv2_steps):
+    """One chip exchanges nothing. Four chips move the routed rows with
+    `ragged-all-to-all`: 4 MoE layers x dispatch and combine x forward,
+    rematerialized forward and transpose; no all-reduce lies under the
+    expert layer's scopes (a receive buffer typed invariant would make
+    AD sum its cotangent across chips)."""
+    from gloo_tpu.tpu import collective_stats
+
+    assert collective_stats(dsv2_steps[1]).calls == {}
+    compiled = dsv2_steps[4]
+    stats = collective_stats(compiled)
+    assert stats.calls["ragged-all-to-all"] == 24
+    assert stats.dtypes["ragged-all-to-all"]["bf16"] > 0
+    reduces = re.findall(r"all-reduce(?:-start)?\(.*op_name=\"([^\"]*)\"",
+                         compiled.as_text())
+    assert reduces and not [n for n in reduces if "gloo_tpu.ep." in n]
