@@ -19,6 +19,7 @@ optax = pytest.importorskip("optax")
 
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
+from jax.test_util import check_grads  # noqa: E402
 
 from benchmark.references import deepseek_v2 as ref  # noqa: E402
 from gloo_tpu.models import DeepSeekV2, DeepSeekV2Config  # noqa: E402
@@ -116,44 +117,123 @@ def test_mla_block_matches_reference_forward_and_grads():
                                    atol=2e-4)
 
 
-def _moe_program(cfg, chips, router, experts, x, first=0):
+def _moe_program(cfg, chips, first=0):
     """`moe` on `chips` devices, experts split over them, first held
-    expert `first`."""
+    expert `first`: f(x, router, w_gate, w_up, w_down) -> (y, probs)."""
     k = cfg["num_experts_per_tok"]
-    held = experts["w_gate"].shape[0]
 
     def local(x, router, wg, wu, wd):
-        base = first + spmd.rank("data") * (held // chips)
-        return moe(x, router, wg, wu, wd, first_expert=base, top_k=k,
-                   axis="data")[0]
+        base = first + spmd.rank("data") * wg.shape[0]
+        y, probs, _ = moe(x, router, wg, wu, wd, first_expert=base,
+                          top_k=k, axis="data")
+        return y, probs
 
-    f = jax.jit(jax.shard_map(
+    return jax.shard_map(
         local, mesh=_mesh(chips),
         in_specs=(P("data"), P(), P("data"), P("data"), P("data")),
-        out_specs=P("data")))
-    return np.asarray(f(x, router, experts["w_gate"], experts["w_up"],
-                        experts["w_down"]))
+        out_specs=(P("data"), P("data")))
 
 
-def _moe_reference(cfg, router, experts, x, first=0):
-    _, w, top = ref.route(cfg, router, x)
-    return np.asarray(ref.routed_experts(cfg, experts, x, w, top - first))
+def _moe_reference(cfg, first=0):
+    """The reference's dense masked sum over the same held experts."""
+    def f(x, router, wg, wu, wd):
+        probs, w, top = ref.route(cfg, router, x)
+        experts = {"w_gate": wg, "w_up": wu, "w_down": wd}
+        return ref.routed_experts(cfg, experts, x, w, top - first), probs
+    return f
+
+
+def _poisoned_ragged_dot(monkeypatch):
+    """`lax.ragged_dot` as a TPU v5e runs it: the rows no group covers, in
+    its output and in its transpose for the rows, hold NaN rather than
+    the CPU's zeros."""
+    plain = jax.lax.ragged_dot
+
+    def poison(v, groups):
+        past = jnp.arange(v.shape[0]) >= groups.sum()
+        return jnp.where(past[:, None], jnp.nan, v)
+
+    @jax.custom_vjp
+    def ragged_dot(lhs, rhs, groups):
+        return poison(plain(lhs, rhs, groups), groups)
+
+    def ragged_dot_fwd(lhs, rhs, groups):
+        return ragged_dot(lhs, rhs, groups), (lhs, rhs, groups)
+
+    def ragged_dot_bwd(res, g):
+        lhs, rhs, groups = res
+        _, vjp = jax.vjp(lambda a, b: plain(a, b, groups), lhs, rhs)
+        d_lhs, d_rhs = vjp(g)
+        return poison(d_lhs, groups), d_rhs, None
+
+    ragged_dot.defvjp(ragged_dot_fwd, ragged_dot_bwd)
+    monkeypatch.setattr(jax.lax, "ragged_dot", ragged_dot)
+
+
+ROUTES = {
+    # name: (router experts held, first held, what the routing does)
+    "all_held": (16, 0, None),   # on one chip every row of the buffer is held
+    "first_8": (8, 0, None),
+    "from_4": (8, 4, None),
+    "none_held": (8, 16, None),  # experts 16-23: no assignment is held
+    "uneven": (8, 4, "ramp"),    # the router favours low ids
+    "nan_past_groups": (8, 0, "nan"),
+}
 
 
 @pytest.mark.parametrize("chips", [1, 4])
-@pytest.mark.parametrize("held,first", [(16, 0), (8, 0), (8, 4)],
-                         ids=["all_held", "first_8", "from_4"])
-def test_expert_layer_matches_dense_reference(chips, held, first):
+@pytest.mark.parametrize("route", list(ROUTES))
+def test_expert_layer_matches_dense_reference(chips, route, monkeypatch):
     """Every token's routed experts' weighted sum, with all 16 router
-    experts held or 8 of them (0-7, 4-11): the program's sort, ragged
-    exchange and grouped matmul give the reference's dense masked sum."""
+    experts held or 8 of them (0-7, 4-11, none), with groups of very
+    different sizes, and with the grouped matmul leaving NaN past its
+    groups as a TPU does: the program's sort, ragged exchange, grouped
+    matmul and combine give the reference's dense masked sum, its router
+    probabilities, and the gradients of both with respect to x, the router
+    and all three expert weights. With nothing held the routed part and
+    every gradient through it are exactly 0."""
+    held, first, how = ROUTES[route]
     cfg = _cfg(n_routed_experts=held)
     moe_p = _params(cfg)["layers"][1]["moe"]
-    x = jax.random.normal(jax.random.key(2), (chips * 24, cfg["n_embd"]))
-    got = _moe_program(cfg, chips, moe_p["router"], moe_p["experts"], x,
-                       first)
-    want = _moe_reference(cfg, moe_p["router"], moe_p["experts"], x, first)
+    e = moe_p["experts"]
+    x = np.array(jax.random.normal(jax.random.key(2),
+                                   (chips * 24, cfg["n_embd"])))
+    router = np.array(moe_p["router"])
+    if how == "ramp":
+        x[:, 0] = 3.0
+        router[0] = np.linspace(0.5, -0.5, router.shape[1])
+    if how == "nan":
+        _poisoned_ragged_dot(monkeypatch)
+    args = (jnp.asarray(x), jnp.asarray(router), e["w_gate"], e["w_up"],
+            e["w_down"])
+    ct = jax.random.normal(jax.random.key(7), x.shape)
+    ct_probs = jax.random.normal(jax.random.key(8),
+                                 (x.shape[0], router.shape[1]))
+
+    def loss(f, with_probs=True):
+        def value(*a):
+            y, probs = f(*a)
+            return jnp.sum(y * ct) + with_probs * jnp.sum(probs * ct_probs)
+        return jax.jit(jax.grad(value, argnums=range(5)))
+
+    program = _moe_program(cfg, chips, first)
+    reference = _moe_reference(cfg, first)
+    (got, got_probs), (want, want_probs) = (jax.jit(f)(*args)
+                                            for f in (program, reference))
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(got_probs, want_probs, rtol=1e-5, atol=1e-7)
+    for a, b, name in zip(loss(program)(*args), loss(reference)(*args),
+                          ["x", "router", "w_gate", "w_up", "w_down"]):
+        np.testing.assert_allclose(a, b, rtol=1e-4,
+                                   atol=1e-5 * np.abs(b).max(), err_msg=name)
+    if how == "ramp":
+        _, _, top = ref.route(cfg, jnp.asarray(router), jnp.asarray(x))
+        sizes = np.bincount(np.asarray(top).ravel(), minlength=16)[4:12]
+        assert sizes.max() >= 4 * max(sizes.min(), 1), sizes
+    if route == "none_held":
+        assert not np.asarray(got).any()
+        assert not any(np.asarray(g).any()
+                       for g in loss(program, with_probs=False)(*args))
 
 
 def test_expert_layer_skewed_routing_drops_nothing():
@@ -171,12 +251,41 @@ def test_expert_layer_skewed_routing_drops_nothing():
     x[:, 0] = 4.0
     _, _, top = ref.route(cfg, jnp.asarray(router), jnp.asarray(x))
     assert set(np.unique(np.asarray(top))) == {0, 1}
-    got = _moe_program(cfg, 4, jnp.asarray(router), moe_p["experts"],
-                       jnp.asarray(x))
-    want = _moe_reference(cfg, jnp.asarray(router), moe_p["experts"],
-                          jnp.asarray(x))
+    e = moe_p["experts"]
+    args = (jnp.asarray(x), jnp.asarray(router), e["w_gate"], e["w_up"],
+            e["w_down"])
+    got = jax.jit(_moe_program(cfg, 4))(*args)[0]
+    want = _moe_reference(cfg)(*args)[0]
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
     assert np.abs(want).min(axis=1).max() > 0
+
+
+@pytest.mark.parametrize("part", ["combine", "sort_rows"])
+def test_permutation_vjps_check_grads(part):
+    """The custom VJPs of the combine and of the rows' sort against finite
+    differences at tiny width, 5 of 12 assignments held: the combine with
+    respect to the sorted rows and the weights, the sort with respect to
+    x through the rows that are held (the others are never read back)."""
+    from gloo_tpu.parallel.ep import _combine, _sort_rows
+
+    t, k, d, held = 4, 3, 5, 4
+    key = jnp.asarray([0, 4, 2, 4, 4, 1, 3, 4, 4, 0, 4, 4])   # 4: not held
+    mine = (key < held).reshape(t, k)
+    order = jnp.argsort(key, stable=True)
+    inverse = jnp.argsort(order).reshape(t, k)
+    live = jnp.arange(t * k) < mine.sum()
+    rng = np.random.RandomState(0)
+    if part == "combine":
+        f = functools.partial(_combine, order=order, inverse=inverse,
+                              mine=mine)
+        args = (jnp.asarray(rng.randn(t * k, d), jnp.float32),
+                jnp.where(mine, jnp.asarray(rng.rand(t, k), jnp.float32), 0))
+    else:
+        def f(x):
+            rows = _sort_rows(x, order, inverse, mine)
+            return jnp.where(live[:, None], rows, 0.0)
+        args = (jnp.asarray(rng.randn(t, d), jnp.float32),)
+    check_grads(f, args, order=1, modes=("rev",), eps=0.5)
 
 
 def test_share_test_eight_shares_make_the_uncut_layer():

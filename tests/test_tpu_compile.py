@@ -266,3 +266,25 @@ def test_dsv2_lite_exchange_is_ragged_and_only_on_four_chips(dsv2_steps):
     reduces = re.findall(r"all-reduce(?:-start)?\(.*op_name=\"([^\"]*)\"",
                          compiled.as_text())
     assert reduces and not [n for n in reduces if "gloo_tpu.ep." in n]
+
+
+def test_dsv2_lite_permutation_stays_in_token_space(dsv2_steps):
+    """On one chip, the top-level instructions under `gloo_tpu.ep.route`
+    and `.combine` (what `ep_route_ms` reads) write no (T, k, D) tensor
+    and at most 16 of the worst-case (T k, D) row buffers: per MoE layer
+    the rows' sort, its rematerialization, dy's rows in sorted order and
+    their cotangent. Validity and weights live in token space, so no mask
+    runs over a buffer."""
+    from benchmark import ep_scopes
+
+    text = dsv2_steps[1].as_text()
+    entry = text[text.index("\nENTRY "):]
+    shapes = []
+    for m in re.finditer(r"^\s*(?:ROOT\s+)?%(\S+) = (\S+) ([a-z][a-z0-9-]*)"
+                         r"\(.*op_name=\"([^\"]*)\"", entry[:entry.index("\n}")],
+                         re.MULTILINE):
+        if ep_scopes.part(f"{m[1]} {m[3]}", m[4]) in ("route", "combine"):
+            shapes.append(re.sub(r"\{[^{}]*\}", "", m[2]))
+    assert shapes
+    assert "bf16[16384,6,2048]" not in shapes
+    assert shapes.count("bf16[98304,2048]") <= 16
