@@ -102,9 +102,10 @@ build/tpucoll_integration: $(FB_BUILD)/tests/integration_main.o $(FB_OBJS)
 	@mkdir -p build
 	$(CXX) -o $@ $^ -lpthread -lrt
 
-# The benchmark CLI (csrc/benchmark/main.cc) — the measurement source of
-# tools/bench_sweep.py and the native-bench pytest wrapper; the cmake
-# build produces it as a first-class target, so the fallback must too.
+# The host plane's native benchmark CLI (csrc/benchmark/main.cc), a port
+# of the reference's latency/bandwidth harness, smoked by
+# tests/test_native_unit.py::test_bench_cli_smoke; the cmake build
+# produces it as a first-class target, so the fallback must too.
 build/tpucoll_bench: $(FB_BUILD)/benchmark/main.o $(FB_OBJS)
 	@mkdir -p build
 	$(CXX) -o $@ $^ -lpthread -lrt

@@ -754,7 +754,7 @@ int tc_fleet_json(void* ctx, uint8_t** out, size_t* outLen) {
 
 // ---- bootstrap plane (boot/, docs/bootstrap.md) ----
 
-// Store-choreography cost model for `bench.py --bootstrap-sweep`: run
+// Store-choreography cost model (tests/test_bootstrap_scale.py): run
 // `nranks` in-process rank threads through ONE bootstrap rendezvous
 // over a shared FileStore rooted at `storePath` (which must be fresh
 // per call — the key schema is fixed). lazy != 0 runs the leader-

@@ -620,7 +620,7 @@ void allreduce(AllreduceOptions& opts) {
       // recursive doubling (log2 P full-vector rounds;
       // non-power-of-2 groups take a pre/post fold) for the
       // alpha-dominated tiny tier, halving-doubling up to ~1 MiB, the
-      // pipelined ring beyond. Re-sweep via bench.py --autotune, or move
+      // pipelined ring beyond. Re-sweep via tuning::tune, or move
       // the fallback thresholds with TPUCOLL_ALLREDUCE_RD_MAX /
       // TPUCOLL_ALLREDUCE_HD_MAX (bytes).
       if (auto tuned = tuning::tableAllreduce(ctx, opts.dtype, nbytes)) {

@@ -84,8 +84,8 @@ const std::vector<collectives_detail::SegSpan>& Plan::segments(
 
 PlanCache::PlanCache(Context* ctx)
     : ctx_(ctx),
-      // Read per-cache (not function-static): bench.py's A/B arms and
-      // the tests toggle the knobs between Context constructions.
+      // Read per-cache (not function-static): the tests toggle the
+      // knobs between Context constructions.
       enabled_(envFlag("TPUCOLL_PLAN_CACHE", true)),
       capacity_(static_cast<size_t>(
           envCount("TPUCOLL_PLAN_LRU", 64, 1, 1 << 20))) {}

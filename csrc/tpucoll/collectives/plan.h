@@ -45,7 +45,7 @@
 //
 // TPUCOLL_PLAN_CACHE=0 disables caching entirely: every call gets a
 // transient Plan whose stages ride the Context scratch pool — byte-for-
-// byte the pre-plan behavior (the A/B arm bench.py --latency measures).
+// byte the pre-plan behavior.
 #pragma once
 
 #include <atomic>

@@ -1,10 +1,10 @@
 // Codec worker pool: the shared thread lanes the wire codecs (math.h
 // q8/q4/bf16 streams) run on when a hop is large enough to shard.
 //
-// PROF_r15.json moved the q8 bottleneck from the wire into the encoder:
-// at 64 MiB pack+unpack is ~62 ms of a ~100 ms op while wire_wait sits
-// at 16 ms. The pool takes the serial codec off the caller's critical
-// path two ways:
+// A phase profile of the q8 ring on a CPU host put its bottleneck in the
+// encoder, not the wire: at 64 MiB pack+unpack was ~62 ms of a ~100 ms
+// op while wire_wait sat at 16 ms. The pool takes the serial codec off
+// the caller's critical path two ways:
 //
 //   - parallelFor(): shard a stream across the caller + workers at
 //     deterministic whole-unit boundaries (collectives/wire_codec.h

@@ -40,8 +40,8 @@ then in later jobs either ``TPUCOLL_SCHEDULE_FILE=sched.json`` (loaded
 and installed at context connect) or::
 >>> schedule.install(ctx, schedule.load("sched.json"))
 
-``bench.py --schedule-sweep`` drives the sweep standalone; see
-docs/schedules.md for the IR, the JSON format, and the election rules.
+See docs/schedules.md for the IR, the JSON format, and the election
+rules.
 """
 
 from __future__ import annotations

@@ -32,9 +32,7 @@ then in later jobs either ``TPUCOLL_TUNING_FILE=prod.json`` (loaded and
 installed at context connect, no code changes) or::
 >>> tuning.install_table(ctx, tuning.load_table("prod.json"))
 
-``bench.py --autotune`` drives the sweep standalone and reports the
-measured deltas against the default thresholds; see docs/tuning.md for
-the table format and election protocol.
+See docs/tuning.md for the table format and election protocol.
 """
 
 from __future__ import annotations
@@ -146,9 +144,10 @@ def set_transport_hints(table: TableLike, channels: Optional[int] = None,
     count and the stripe threshold (docs/transport.md). A context that
     installs the table (or loads it via TPUCOLL_TUNING_FILE) applies
     them at connect time unless the TPUCOLL_CHANNELS /
-    TPUCOLL_STRIPE_BYTES env overrides them. Pick the values from a
-    ``bench.py --channel-sweep`` run on the target host. Returns the
-    table as a dict. The same every-rank-same-table contract applies:
+    TPUCOLL_STRIPE_BYTES env overrides them. Pick the values by timing
+    ``tpucoll_bench`` on the target host under each setting of those two
+    variables. Returns the table as a dict. The same
+    every-rank-same-table contract applies:
     channel counts must agree across ranks or connect fails loudly."""
     t = json.loads(_to_json_str(table))
     hints = dict(t.get("transport", {}))

@@ -7,7 +7,7 @@ two forms a production deployment actually consumes:
 - `to_prometheus(snapshot)` renders the Prometheus text exposition format
   (serve it from a /metrics endpoint or push it through a gateway);
 - `histogram_quantile(hist, q)` estimates latency quantiles from the
-  fixed power-of-two buckets (p50/p95 for dashboards and bench output);
+  fixed power-of-two buckets (p50/p95 for dashboards);
 - `merge_snapshots(snaps)` sums per-rank snapshots into a job-level view.
 
 The native histograms store per-bucket (non-cumulative) counts as
@@ -43,8 +43,8 @@ def histogram_quantile(hist: dict, q: float) -> float:
 
 
 def summarize_ops(snapshot: dict) -> Dict[str, dict]:
-    """Per-op {calls, bytes, errors, p50_us, p95_us, mean_us} digest —
-    the compact form bench.py embeds in its JSON line."""
+    """Per-op {calls, bytes, errors, p50_us, p95_us, mean_us} digest of
+    a snapshot: the compact form for a log line or a dashboard."""
     out = {}
     for name, s in snapshot.get("ops", {}).items():
         hist = s.get("latency_us", {})

@@ -4,8 +4,7 @@ over the host topology, and per-host lease aggregation for the elastic
 coordinator — the P>=512 bring-up story, exercised here at CI scale
 with simulated hosts (TPUCOLL_HOST_ID / set_host_id).
 
-The native choreography curves live in BOOT_r18.json (bench.py
---bootstrap-sweep); these tests pin the *semantics*: every algorithm
+These tests pin the *semantics*: every algorithm
 family (and the PR 17 schedule interpreter) runs unchanged over a
 broker-dialed mesh, the steady-state broker pair count respects
 TPUCOLL_MAX_PAIRS, first-use dial failures surface as typed errors

@@ -403,8 +403,13 @@ print("OK", json.dumps({{"acc": float(res["state"]["acc"]),
                          "rebuilds": res["rebuilds"],
                          "sizes": [e["size"] for e in res["epochs"]]}}))
 """.format(ckdir=ckdir)
-    procs = [_spawn(body, r, 3, store,
-                    extra_env={"JAX_PLATFORMS": "cpu"}) for r in range(3)]
+    # Each rank imports jax and orbax before its first step; on a loaded
+    # host the file's 1.2 s grace could expire rank 2's lease before its
+    # planned SIGKILL. A 6 s grace still detects the kill well inside
+    # the step timeout.
+    env = {"JAX_PLATFORMS": "cpu", "TPUCOLL_LEASE_MS": "500",
+           "TPUCOLL_LEASE_GRACE": "6000"}
+    procs = [_spawn(body, r, 3, store, extra_env=env) for r in range(3)]
     outs = [p.communicate(timeout=240) for p in procs]
     assert procs[2].returncode == -signal.SIGKILL
     results = []

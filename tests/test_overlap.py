@@ -198,8 +198,8 @@ def test_matmul_reduce_scatter_multi_axis_mesh():
 
 
 def test_virtual_ring_selfloop_bench_path():
-    """The single-chip bench mode (virtual_ranks on a 1-device axis,
-    tools/tpu_bench.py --op overlap): self-loop RDMA means every hop
+    """The single-chip timing mode (virtual_ranks on a 1-device axis):
+    self-loop RDMA means every hop
     adds this rank's own staged sum, so matmul_rs degenerates to
     sum over row-blocks of X_b @ W and allgather_matmul's own chunk
     is exact. Guards the timing harness against schedule rot."""

@@ -41,8 +41,7 @@ class EnvHygieneRule(Rule):
     env_header = ENV_HEADER
     cpp_roots = ("csrc/tpucoll/**/*.cc", "csrc/tpucoll/**/*.h",
                  "csrc/tpucoll/*.cc", "csrc/tpucoll/*.h")
-    py_roots = ("gloo_tpu/**/*.py", "gloo_tpu/*.py", "bench.py",
-                "tools/*.py")
+    py_roots = ("gloo_tpu/**/*.py", "gloo_tpu/*.py", "tools/*.py")
     doc_roots = ("docs/*.md", "README.md")
 
     def run(self, corpus: Corpus) -> List[Violation]:

@@ -15,8 +15,8 @@ Method per trial: clear the jit cache; time plain dot; time fused
 compile A; re-time compile A's SAME objects (run-noise bound); time a
 second fresh compile B (in-process compile-draw bound). Chains are
 sized to >0.25 s of differenced work so dispatch and fetch noise
-cancels. The chain length is FIXED (unlike tpu_bench's adaptive
-`_chain_rate`, deliberately): compiles A and B must be timed over
+cancels. The chain length is FIXED, deliberately, not adapted to the
+rate: compiles A and B must be timed over
 identical chain lengths or the comparison confounds chain growth with
 the compile draw it exists to isolate.
 
