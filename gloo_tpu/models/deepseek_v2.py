@@ -59,7 +59,8 @@ class DeepSeekV2Config:
     yarn_beta_slow: float = 1.0
     yarn_mscale_all_dim: float = 0.707
     dtype: Any = jnp.bfloat16
-    # Rematerialize each decoder layer in the backward pass.
+    # Rematerialize each decoder layer in the backward pass, all but the
+    # flash attention's output and logsumexp.
     remat: bool = True
     # The mesh axis the routed experts are split over (None: all here).
     ep_axis: str | None = None
@@ -158,25 +159,32 @@ class DeepSeekV2:
                              c.yarn_original_positions, c.yarn_beta_fast,
                              c.yarn_beta_slow)
 
-    def _mla(self, p, x):
+    def rope_table(self, t: int):
+        """cos and sin of positions 0..t-1's rotary angles, (t, qk_rope_dim
+        // 2) f32 each."""
+        from gloo_tpu.ops.rope import rope_positions, rope_table
+
+        return rope_table(rope_positions(t), self.cfg.qk_rope_dim,
+                          inv_freq=self.inv_freq())
+
+    def _mla(self, p, x, table):
         from gloo_tpu.ops.attention import flash_attention
-        from gloo_tpu.ops.rope import apply_rope, rope_positions
+        from gloo_tpu.ops.rope import rotate
 
         c = self.cfg
         b, t, _ = x.shape
         h, dn, dr, dv = c.n_heads, c.qk_nope_dim, c.qk_rope_dim, c.v_dim
         dt = x.dtype
-        pos, freq = rope_positions(t), self.inv_freq()
         q = (x @ p["wq"].astype(dt)).reshape(b, t, h, dn + dr)
         q = q.transpose(0, 2, 1, 3)
         kv_a = x @ p["wkv_a"].astype(dt)
         latent = self._rmsnorm(kv_a[..., :c.kv_lora_rank],
                                p["kv_norm"]["scale"])
-        k_pe = apply_rope(kv_a[..., c.kv_lora_rank:], pos, inv_freq=freq)
+        k_pe = rotate(kv_a[..., c.kv_lora_rank:], table)
         kv = (latent @ p["wkv_b"].astype(dt)).reshape(b, t, h, dn + dv)
         kv = kv.transpose(0, 2, 1, 3)
         q = jnp.concatenate(
-            [q[..., :dn], apply_rope(q[..., dn:], pos, inv_freq=freq)],
+            [q[..., :dn], rotate(q[..., dn:], table)],
             axis=-1) * jnp.asarray(self.mscale() ** 2, dt)
         k = jnp.concatenate(
             [kv[..., :dn], jnp.broadcast_to(k_pe[:, None], (b, h, t, dr))],
@@ -220,10 +228,11 @@ class DeepSeekV2:
         return c.aux_loss_alpha * jnp.mean(
             jnp.sum(f * jnp.mean(probs, axis=1), axis=-1))
 
-    def _layer(self, p, x):
+    def _layer(self, p, x, table):
         with jax.named_scope("gloo_tpu.mla"):
             x = x + self._mla(p["mla"],
-                              self._rmsnorm(x, p["attn_norm"]["scale"]))
+                              self._rmsnorm(x, p["attn_norm"]["scale"]),
+                              table)
         h = self._rmsnorm(x, p["ffn_norm"]["scale"])
         if "mlp" in p:
             return x + self._swiglu(p["mlp"], h), jnp.zeros((), jnp.float32)
@@ -233,12 +242,27 @@ class DeepSeekV2:
     def apply(self, params, tokens):
         """tokens (B, T) int32 -> (logits (B, T, vocab) f32, the summed
         balance loss of the MoE layers)."""
+        from gloo_tpu.ops.attention import save_flash_residuals
+
         c = self.cfg
         x = params["embed"][tokens].astype(c.dtype)
-        layer = jax.checkpoint(self._layer) if c.remat else self._layer
+        table = self.rope_table(tokens.shape[1])
+        layer = self._layer
+        if c.remat:
+            # Remat keeps the flash kernel's out and logsumexp and
+            # recomputes the rest of the layer, so the recompute must give
+            # the q, k, v that made them, bit for bit. XLA keeps a fused
+            # bf16 sum unrounded: the barrier starts the forward from the
+            # rounded input the checkpoint keeps. The rope table comes
+            # from outside: made inside the layer, the forward's fusions
+            # and the recompute's computed cos and sin differently.
+            remat = jax.checkpoint(self._layer, policy=save_flash_residuals)
+
+            def layer(p, x, table):
+                return remat(p, jax.lax.optimization_barrier(x), table)
         aux = jnp.zeros((), jnp.float32)
         for p in params["layers"]:
-            x, a = layer(p, x)
+            x, a = layer(p, x, table)
             aux = aux + a
         x = self._rmsnorm(x, params["ln_f"]["scale"])
         return x.astype(jnp.float32) @ params["head"], aux

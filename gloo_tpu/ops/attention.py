@@ -23,8 +23,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+# The names of the flash forward's two residuals, out and logsumexp; the
+# policy that keeps them (and only them) through `jax.checkpoint`.
+FLASH_OUT = "gloo_tpu.flash.out"
+FLASH_LSE = "gloo_tpu.flash.lse"
+save_flash_residuals = jax.checkpoint_policies.save_only_these_names(
+    FLASH_OUT, FLASH_LSE)
 
 
 def _score_tile_global(q_ref, k_ref, q_base, k_base, block_q, block_k,
@@ -177,6 +185,15 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = None,
     softmax tiles from the saved logsumexp rows — no (T, T)
     materialization anywhere in training.
 
+    The forward's residuals out and logsumexp carry the names FLASH_OUT
+    and FLASH_LSE (`checkpoint_name`, the identity outside
+    `jax.checkpoint`). A caller that rematerializes a layer around this
+    call with `policy=save_flash_residuals` keeps the two and recomputes
+    the rest, so its backward runs the forward kernel once, not twice;
+    the cost is both held from the forward to the backward: out at q's
+    size and dtype, and the f32 (b*h, seq, 1) logsumexp, which TPU
+    tiling pads to 128 lanes, i.e. b*h*seq*512 bytes.
+
     Inside shard_map the outputs vary over vma_axes; by default those are
     the axes q, k and v vary over, so a caller such as a model under
     make_ddp_train_step need not name them."""
@@ -215,6 +232,8 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = None,
 
     def fwd(qf, kf, vf):
         out, lse = run_kernel(qf, kf, vf)
+        out = checkpoint_name(out, FLASH_OUT)
+        lse = checkpoint_name(lse, FLASH_LSE)
         return out, (qf, kf, vf, out, lse)
 
     def bwd(residuals, g):

@@ -55,14 +55,26 @@ def yarn_inv_freq(head_dim: int, theta: float, factor: float,
     return extra / factor * ramp + extra * (1.0 - ramp)
 
 
+def rope_table(positions, head_dim: int, theta: float = 10000.0,
+               inv_freq=None):
+    """(cos, sin) of `rope_angles`, f32, each (..., t, head_dim // 2): made
+    once, one table rotates every layer alike (`rotate`)."""
+    ang = rope_angles(positions, head_dim, theta, inv_freq)
+    return jnp.cos(ang), jnp.sin(ang)
+
+
 def apply_rope(x, positions, theta: float = 10000.0, inv_freq=None):
     """Rotate x: (..., t, head_dim) by its positions: (t,) or broadcastable
     to x's leading dims + (t,); `inv_freq` (head_dim // 2,) in place of
     theta's. Returns x's dtype."""
+    return rotate(x, rope_table(positions, x.shape[-1], theta, inv_freq))
+
+
+def rotate(x, table):
+    """Rotate x: (..., t, head_dim) by a `rope_table`. Returns x's
+    dtype."""
+    cos, sin = table
     d = x.shape[-1]
-    ang = rope_angles(positions, d, theta, inv_freq)  # (..., t, d//2)
-    cos = jnp.cos(ang).astype(jnp.float32)
-    sin = jnp.sin(ang).astype(jnp.float32)
     x1 = x[..., : d // 2].astype(jnp.float32)
     x2 = x[..., d // 2:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],

@@ -13,6 +13,7 @@ from many gradients counts as one call carrying all of their bytes.
     stats.calls["all-reduce"], stats.bytes["all-reduce"] / 1e6  # calls, MB
     stats.dtypes["all-reduce"]           # {"f32": bytes, "bf16": bytes}
     stats.op_names["psum_invariant.616"]  # the instruction's op_name
+    stats.pallas_calls["flash_attention"]  # the flash forward's launches
 
 Counts are static: a collective inside a loop body counts once, and a
 `ragged-all-to-all` counts its whole operand and output buffers (sized
@@ -34,6 +35,7 @@ _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (.*)$")
 _OPCODE = re.compile(r"(?:^|\s)([a-z][a-z0-9_\-]*)\(")
 _ARRAY = re.compile(r"\b([a-z][a-z0-9]*)\[([\d,]*)\]")
 _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_JIT = re.compile(r"jit\(([\w.\-]+)\)")
 
 
 @dataclass(frozen=True)
@@ -42,12 +44,17 @@ class CollectiveStats:
     `bytes[opcode]` and their split by element type `dtypes[opcode]`, for
     each collective opcode the program issues; `op_names[instruction]`,
     the `op_name` metadata (JAX's name stack) of every instruction that
-    has one."""
+    has one; `pallas_calls[name]`, the Pallas kernel launches by the
+    innermost `jit(...)` of their `op_name`, the jitted function that
+    launched them: `flash_attention` for the flash forward (its
+    rematerialized copies too), `flash_attention_bwd_fused` for its
+    backward."""
 
     calls: dict = field(default_factory=dict)
     bytes: dict = field(default_factory=dict)
     dtypes: dict = field(default_factory=dict)
     op_names: dict = field(default_factory=dict)
+    pallas_calls: dict = field(default_factory=dict)
 
 
 def array_bytes(type_text: str) -> dict:
@@ -77,7 +84,7 @@ def _operands(rhs: str, start: int) -> list:
 def collective_stats(compiled) -> CollectiveStats:
     """Collective calls and operand bytes of one run of `compiled` (a
     `jax.stages.Compiled`, or the text of its `as_text()`) on one device,
-    and every instruction's `op_name`."""
+    every instruction's `op_name`, and its Pallas kernel launches."""
     text = compiled if isinstance(compiled, str) else compiled.as_text()
     types, found = {}, []
     stats = CollectiveStats()
@@ -93,6 +100,10 @@ def collective_stats(compiled) -> CollectiveStats:
         named = _OP_NAME.search(rhs)
         if named:
             stats.op_names[name] = named.group(1)
+        if op.group(1) == "custom-call" and named and \
+                named.group(1).endswith("/pallas_call"):
+            kernel = (_JIT.findall(named.group(1)) or ["pallas_call"])[-1]
+            stats.pallas_calls[kernel] = stats.pallas_calls.get(kernel, 0) + 1
         if op.group(1) in OPCODES:
             found.append((op.group(1), _operands(rhs, op.end() - 1)))
     for opcode, operands in found:

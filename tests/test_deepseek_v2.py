@@ -43,7 +43,7 @@ def _cfg(**over):
     return cfg
 
 
-def _model(cfg, axis=None, dtype=jnp.float32):
+def _model(cfg, axis=None, dtype=jnp.float32, remat=True):
     y = cfg["rope_scaling"]
     return DeepSeekV2(DeepSeekV2Config(
         vocab_size=cfg["vocab_size"], d_model=cfg["n_embd"],
@@ -60,7 +60,8 @@ def _model(cfg, axis=None, dtype=jnp.float32):
         qk_rope_dim=cfg["qk_rope_head_dim"], v_dim=cfg["v_head_dim"],
         rope_theta=cfg["rope_theta"], yarn_factor=y["factor"],
         yarn_original_positions=y["original_max_position_embeddings"],
-        yarn_mscale_all_dim=y["mscale_all_dim"], dtype=dtype, ep_axis=axis))
+        yarn_mscale_all_dim=y["mscale_all_dim"], dtype=dtype, remat=remat,
+        ep_axis=axis))
 
 
 def _params(cfg, seed=5):
@@ -100,21 +101,72 @@ def test_mla_block_matches_reference_forward_and_grads():
     model = _model(cfg)
     p = _params(cfg)["layers"][0]["mla"]
     x = jax.random.normal(jax.random.key(1), (2, 32, cfg["n_embd"]))
-    rope = ref.yarn(cfg, 32)
+    rope, table = ref.yarn(cfg, 32), model.rope_table(32)
 
     def prog(p):
-        return jnp.sum(model._mla(p, x) ** 2)
+        return jnp.sum(model._mla(p, x, table) ** 2)
 
     def plain(p):
         return jnp.sum(ref._mla(p, x, cfg, rope, None) ** 2)
 
-    np.testing.assert_allclose(np.asarray(model._mla(p, x)),
+    np.testing.assert_allclose(np.asarray(model._mla(p, x, table)),
                                np.asarray(ref._mla(p, x, cfg, rope, None)),
                                rtol=2e-4, atol=2e-5)
     got, want = jax.grad(prog)(p), jax.grad(plain)(p)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3,
                                    atol=2e-4)
+
+
+def _remat_case():
+    """A 3-layer configuration, its params and a (2, 32) batch."""
+    cfg = _cfg(n_layer=3)
+    tokens = jax.random.randint(jax.random.key(2), (2, 32), 0,
+                                cfg["vocab_size"])
+    return cfg, _params(cfg), (tokens, jnp.roll(tokens, -1, axis=1))
+
+
+def test_remat_keeps_the_flash_residuals():
+    """With each layer rematerialized, the gradient launches one flash
+    forward and one fused backward kernel a layer: the remat keeps the
+    forward's out and logsumexp, so its recompute runs no forward kernel.
+    The gradients are those of the model without remat."""
+    from test_flash_attention import pallas_kernels
+
+    cfg, params, batch = _remat_case()
+    model = _model(cfg)
+    jaxpr = jax.make_jaxpr(jax.grad(model.loss))(params, batch)
+    assert pallas_kernels(jaxpr.jaxpr) == {"_flash_kernel": 3,
+                                           "_flash_bwd_fused_kernel": 3}
+    got = jax.grad(model.loss)(params, batch)
+    want = jax.grad(_model(cfg, remat=False).loss)(params, batch)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-7)
+
+
+def test_remat_recomputes_the_forward_bitwise_in_bf16(monkeypatch):
+    """In bf16 the rematerialized gradient is the one the forward defines:
+    that of the same model with every forward value kept. XLA may keep a
+    fused bf16 sum unrounded; each layer's recompute starts from the
+    rounded input the checkpoint keeps, so the forward has to as well, or
+    the q, k, v the flash backward gets differ from those that made the
+    kept out and logsumexp (leaves 1-6% apart, here on the CPU)."""
+    from gloo_tpu.ops import attention
+
+    cfg, params, batch = _remat_case()
+
+    def grads():
+        model = _model(cfg, dtype=jnp.bfloat16)
+        return jax.tree.leaves(jax.jit(jax.grad(model.loss))(params, batch))
+
+    got = grads()
+    monkeypatch.setattr(attention, "save_flash_residuals",
+                        jax.checkpoint_policies.everything_saveable)
+    for a, b in zip(got, grads()):
+        b = np.asarray(b)
+        np.testing.assert_allclose(np.asarray(a), b, rtol=0,
+                                   atol=1e-6 * np.max(np.abs(b)))
 
 
 def _moe_program(cfg, chips, first=0):

@@ -130,6 +130,30 @@ ENTRY %main (x: f32[8], y: bf16[2,4]) -> f32[8] {
                               "ars": "jit(f)/psum"}
 
 
+def test_collective_stats_tallies_pallas_calls_by_function():
+    """Pallas launches (custom calls whose op_name ends in `pallas_call`)
+    count under the innermost `jit(...)` of their op_name, rematerialized
+    copies too; the instructions around them that share the op_name,
+    other Mosaic custom calls and other custom calls do not count."""
+    call = 'custom-call(%x), custom_call_target="tpu_custom_call"'
+    fwd = 'jit(step)/jvp(jit(flash_attention))/pallas_call'
+    text = f"""HloModule m
+
+ENTRY %main (x: bf16[8]) -> bf16[8] {{
+  %x = bf16[8]{{0}} parameter(0)
+  %flash_attention.1 = (bf16[8]{{0}}) {call}, metadata={{op_name="{fwd}"}}
+  %gte.1 = bf16[8]{{0}} get-tuple-element(%flash_attention.1), index=0, metadata={{op_name="{fwd}"}}
+  %flash_attention.2 = bf16[8]{{0}} {call}, metadata={{op_name="jit(step)/checkpoint/rematted_computation/jit(flash_attention)/pallas_call"}}
+  %flash_attention_bwd_fused.3 = bf16[8]{{0}} {call}, metadata={{op_name="jit(step)/transpose(jvp(jit(flash_attention)))/jit(flash_attention_bwd_fused)/pallas_call"}}
+  %ragged-dot-none.4 = bf16[8]{{0}} {call}, metadata={{op_name="jit(step)/jit(moe)/ragged_dot_general"}}
+  %cc.5 = bf16[8]{{0}} custom-call(%x), custom_call_target="Sharding"
+  ROOT %r = bf16[8]{{0}} add(%x, %x)
+}}
+"""
+    assert collective_stats(text).pallas_calls == {
+        "flash_attention": 2, "flash_attention_bwd_fused": 1}
+
+
 @pytest.mark.parametrize("text,expected", [
     ("(bf16[768,2304]{1,0:T(8,128)(2,1)}, f32[]{:T(128)})",
      {"bf16": 768 * 2304 * 2, "f32": 4}),

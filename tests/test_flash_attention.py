@@ -8,7 +8,7 @@ jax = pytest.importorskip("jax")
 
 import jax.numpy as jnp  # noqa: E402
 
-from gloo_tpu.ops import flash_attention  # noqa: E402
+from gloo_tpu.ops import flash_attention, save_flash_residuals  # noqa: E402
 from gloo_tpu.ops.attention import _reference_attention  # noqa: E402
 
 
@@ -246,3 +246,63 @@ def test_flash_large_square_tiles_match(t, bq):
                                      block_k=bq, interpret=True))
     ref = np.asarray(_reference_attention(q, k, v, causal=True))
     np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-5)
+
+
+def pallas_kernels(jaxpr) -> dict:
+    """{kernel function: pallas_call count} in a jaxpr and every jaxpr
+    nested in it (jit, custom VJP, remat): the forward is `_flash_kernel`,
+    the fused backward `_flash_bwd_fused_kernel`."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    found = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            src = eqn.params["jaxpr"].debug_info.func_src_info
+            found[src.split(" ")[0]] = found.get(src.split(" ")[0], 0) + 1
+        for value in eqn.params.values():
+            for x in value if isinstance(value, (tuple, list)) else [value]:
+                if isinstance(x, (ClosedJaxpr, Jaxpr)):
+                    inner = x.jaxpr if isinstance(x, ClosedJaxpr) else x
+                    for name, n in pallas_kernels(inner).items():
+                        found[name] = found.get(name, 0) + n
+    return found
+
+
+@pytest.mark.parametrize("policy,forwards",
+                         [(save_flash_residuals, 1), (None, 2)])
+def test_remat_with_save_flash_residuals_runs_the_forward_once(policy,
+                                                               forwards):
+    """Under `jax.checkpoint(policy=save_flash_residuals)` the gradient
+    keeps the forward's named out and logsumexp and launches the forward
+    kernel once; with no policy the recompute launches it again. The
+    gradients are the same either way."""
+    rng = np.random.RandomState(3)
+    q, k, v = (jnp.asarray(rng.randn(1, 2, 64, 32), jnp.float32)
+               for _ in range(3))
+
+    def layer(q, k, v):
+        return jnp.sum(jnp.tanh(flash_attention(q, k, v, interpret=True)))
+
+    remat = jax.checkpoint(layer, policy=policy)
+    grad = jax.grad(remat, argnums=(0, 1, 2))
+    assert pallas_kernels(jax.make_jaxpr(grad)(q, k, v).jaxpr) == {
+        "_flash_kernel": forwards, "_flash_bwd_fused_kernel": 1}
+    for a, b in zip(grad(q, k, v),
+                    jax.grad(layer, argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                                   atol=1e-7)
+
+
+def test_transformer_gradient_runs_each_flash_kernel_once_a_layer():
+    """The Transformer does not remat: its gradient launches one forward
+    and one fused backward kernel a layer."""
+    from gloo_tpu.models import Transformer, TransformerConfig
+
+    model = Transformer(TransformerConfig(
+        vocab_size=64, d_model=64, n_heads=2, n_layers=3, d_ff=128,
+        max_seq_len=64, dtype=jnp.float32, use_flash_attention=True))
+    params = model.init(jax.random.PRNGKey(0))
+    tokens = jnp.zeros((2, 64), jnp.int32)
+    jaxpr = jax.make_jaxpr(jax.grad(model.loss))(params, (tokens, tokens))
+    assert pallas_kernels(jaxpr.jaxpr) == {"_flash_kernel": 3,
+                                           "_flash_bwd_fused_kernel": 3}

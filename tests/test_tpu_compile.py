@@ -214,6 +214,16 @@ def test_gpt2_small_step_unchanged_by_explicit_replicated_specs(
     assert hlo() == hlo(param_specs=specs)
 
 
+def test_gpt2_small_step_runs_each_flash_kernel_once_a_layer(
+        gpt2_small_step):
+    """No remat: 12 flash forwards and 12 fused backwards a step."""
+    from gloo_tpu.tpu import collective_stats
+
+    calls = collective_stats(gpt2_small_step).pallas_calls
+    assert (calls["flash_attention"],
+            calls["flash_attention_bwd_fused"]) == (12, 12)
+
+
 DSV2_CHIPS = (1, 4)
 
 
@@ -248,6 +258,18 @@ def test_dsv2_lite_step_fits(dsv2_steps, chips):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB per chip"
+
+
+@pytest.mark.parametrize("chips", DSV2_CHIPS)
+def test_dsv2_lite_remat_keeps_the_flash_residuals(dsv2_steps, chips):
+    """Each of the 5 rematerialized layers launches one flash forward and
+    one fused backward: the remat keeps the forward's out and logsumexp,
+    so the backward's recompute runs no second forward."""
+    from gloo_tpu.tpu import collective_stats
+
+    calls = collective_stats(dsv2_steps[chips]).pallas_calls
+    assert (calls["flash_attention"],
+            calls["flash_attention_bwd_fused"]) == (5, 5)
 
 
 def test_dsv2_lite_exchange_is_ragged_and_only_on_four_chips(dsv2_steps):
